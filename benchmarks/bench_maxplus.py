@@ -77,11 +77,8 @@ def _full_row(**cells) -> dict:
 
 
 def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def _data(n: int, cap: int):

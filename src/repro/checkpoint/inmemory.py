@@ -38,10 +38,12 @@ class InMemoryStore:
     def neighbor(self, rank: int) -> int:
         return (rank + 1) % self.n_ranks
 
-    def put(self, task: str, rank: int, step: int, tree: Any) -> None:
+    def put(self, task: str, rank: int, step: int, tree: Any) -> Any:
+        """Store a host snapshot of ``tree``; returns the snapshot."""
         snap = _snapshot(tree)
         self._local[(task, rank)] = (step, snap)
         self._replica[(task, self.neighbor(rank))] = (step, snap)
+        return snap
 
     def drop_rank(self, task: str, rank: int) -> None:
         """Simulate host loss: local copy and any replica *held on* the

@@ -35,10 +35,11 @@ class CheckpointManager:
     def save(self, rank: int, step: int, state: Any) -> None:
         """In-memory snapshot every call; async spool to persistent tier
         every ``persist_every`` steps (synchronous here; the simulator
-        models the asynchrony)."""
-        self.store.put(self.task, rank, step, state)
+        models the asynchrony).  The persistent tier writes the host
+        snapshot, so the state crosses from the device once."""
+        snap = self.store.put(self.task, rank, step, state)
         if step % self.persist_every == 0:
-            persistent.save(self.directory, step, state)
+            persistent.save(self.directory, step, snap)
 
     # ---- restore path (nearest principle) ---------------------------------
 

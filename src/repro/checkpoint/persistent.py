@@ -7,18 +7,30 @@ examples and tests.
 """
 from __future__ import annotations
 
+import json
 import os
 from typing import Any, Optional
 
 import jax
 import numpy as np
 
+# npz has no code for the ml_dtypes (bfloat16, float8, ...): their
+# numpy dtype kind is "V", which ``np.load`` hands back as raw void
+# bytes.  Such leaves are stored bit for bit as unsigned integers of the
+# same width, and this entry maps each of their keys to its dtype name.
+_DTYPES_KEY = "__dtypes__"
+
 
 def _flatten(tree) -> dict:
-    flat = {}
+    flat, dtypes = {}, {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = jax.tree_util.keystr(path)
-        flat[key] = np.asarray(leaf)
+        arr = np.asarray(leaf)
+        if arr.dtype.kind == "V":
+            dtypes[key] = arr.dtype.name
+            arr = arr.view(f"u{arr.dtype.itemsize}")
+        flat[key] = arr
+    flat[_DTYPES_KEY] = np.asarray(json.dumps(dtypes))
     return flat
 
 
@@ -84,14 +96,18 @@ def restore(directory: str, like: Any, step: Optional[int] = None) -> Any:
         if step is None:
             raise FileNotFoundError(f"no checkpoint in {directory}")
     path = os.path.join(directory, f"ckpt_{step:08d}.npz")
-    data = np.load(path)
     leaves_with_path = jax.tree_util.tree_flatten_with_path(like)[0]
     new_leaves = []
-    for p, leaf in leaves_with_path:
-        key = jax.tree_util.keystr(p)
-        arr = data[key]
-        new_leaves.append(np.asarray(arr).astype(leaf.dtype)
-                          if hasattr(leaf, "dtype") else arr)
+    with np.load(path) as data:
+        dtypes = (json.loads(str(data[_DTYPES_KEY]))
+                  if _DTYPES_KEY in data.files else {})
+        for p, leaf in leaves_with_path:
+            key = jax.tree_util.keystr(p)
+            arr = data[key]
+            if key in dtypes:
+                arr = arr.view(jax.numpy.dtype(dtypes[key]))
+            new_leaves.append(arr.astype(leaf.dtype)
+                              if hasattr(leaf, "dtype") else arr)
     return jax.tree_util.tree_unflatten(_treedef_of(like), new_leaves)
 
 

@@ -822,9 +822,11 @@ class _FusedProgram:
     argmax limits are the only runtime inputs; the step tables are
     trace-time constants.  Returns host arrays: the (n_slots, n+1) slot
     values, per-scenario argmax cells, and totals.  Traced and invoked
-    under ``jax.experimental.enable_x64`` so the default backend stays
-    float64 — totals are then bitwise-identical to the numpy engines
-    (each candidate is a single IEEE add; max is order-free).  Under the
+    under ``jax.enable_x64(True)`` so the default backend stays
+    float64 — on a backend with IEEE float64 (the CPU) totals are then
+    bitwise-identical to the numpy engines (each candidate is a single
+    IEEE add; max is order-free).  A TPU v5e emulates float64 with pairs
+    of float32, and there totals agree to ~4e-15 relative.  Under the
     pallas backend the inner step is ``maxplus_scan_chunk`` (float32
     kernel arithmetic, float64 buffer), matching the batched engine's
     pallas precision exactly."""
@@ -839,11 +841,8 @@ class _FusedProgram:
 
     def traces(self) -> int:
         """Compiled-trace count of the jitted program (the no-retrace
-        assertion probe); -1 if this jax build has no cache probe."""
-        try:
-            return int(self._fn._cache_size())
-        except AttributeError:
-            return -1
+        assertion probe)."""
+        return int(self._fn._cache_size())
 
     def _program(self, g_unf, g_f, limits):
         jax = self._jax
@@ -890,8 +889,7 @@ class _FusedProgram:
 
     def __call__(self, g_unf: np.ndarray, g_f: np.ndarray,
                  limits: np.ndarray):
-        from jax.experimental import enable_x64
-        with enable_x64():                # trace AND dispatch in f64
+        with self._jax.enable_x64(True):  # trace AND dispatch in f64
             vals, js, totals = self._fn(g_unf, g_f, limits)
             out = (np.asarray(vals), np.asarray(js), np.asarray(totals))
         self.calls += 1

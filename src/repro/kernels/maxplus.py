@@ -2,22 +2,24 @@
 
     out[j] = max_{0 <= k <= min(j, band)} prev[j-k] + g[k]
 
-One grid program per ``block`` output cells; the padded ``prev`` vector
-and the reward row ``g`` sit whole in VMEM (they are O(n) f32 — a few KB
-at planner scale), and the kernel folds the band with a ``fori_loop`` of
-fused shift+add+max steps, so no (n x n) candidate matrix ever exists in
-any memory space.  Follows the repo's execution-mode policy
-(``pallas_config``): compiled via Mosaic on TPU, interpreted on CPU/GPU,
-``REPRO_PALLAS_INTERPRET``/kwarg override.
+One kernel serves every entry point.  Its grid is (row tiles, output
+blocks): each program owns 8 stacked rows (the sublane tile) and 128
+output cells (the lane tile).  The padded ``prev`` rows and the reward
+rows ``g`` sit whole in VMEM (they are O(n) f32 — a few KB at planner
+scale), and the kernel folds the band with a ``fori_loop`` of fused
+rotate+add+max steps, so no (n x n) candidate matrix ever exists in any
+memory space and every block is (8, 128)-aligned, as Mosaic requires.
+Follows the repo's execution-mode policy (``pallas_config``): compiled
+via Mosaic on TPU, interpreted on CPU/GPU, ``REPRO_PALLAS_INTERPRET``/
+kwarg override.
 
-``maxplus_conv_batched`` is the grid-batched variant behind the
+``maxplus_conv_batched`` is the stacked entry behind the
 ``engine="batched"`` PlanTable: a (B, n+1) stack of independent
-convolutions with per-row bands runs as ONE ``pallas_call`` whose grid
-carries the stack axis — grid (B, n_blocks), each program reading only
-its own row's padded ``prev``/``g`` block.  Per-row bands are applied by
-masking each ``g`` row to -inf past its band (value-neutral: a masked
-candidate can never beat the always-present finite k=0 candidate), so
-every row equals the 2-D kernel on its own slice.
+convolutions with per-row bands runs as ONE ``pallas_call``.  Per-row
+bands are applied by masking each ``g`` row to -inf past its band
+(value-neutral: a masked candidate can never beat the always-present
+finite k=0 candidate), so every row equals the 1-D ``maxplus_conv`` on
+its own slice.
 
 ``maxplus_scan_chunk`` is the scan-compatible entry the fused
 one-program planner engine (``engine="fused"``) uses as its inner step:
@@ -45,41 +47,80 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pallas_config import resolve_interpret
 
 NEG = float("-inf")
+ROWS = 8      # sublane tile: stacked rows per grid program
+LANES = 128   # lane tile: output cells per grid program
 
 
-def _maxplus_kernel(prev_ref, g_ref, o_ref, *, band: int, block: int):
-    """o[dj] = max_k prev_pad[pid*block + band + dj - k] + g[k]."""
-    j0 = pl.program_id(0) * block
-
-    def body(k, acc):
-        w = prev_ref[0, pl.ds(j0 + band - k, block)]     # prev[j0+dj-k]
-        gk = g_ref[0, pl.ds(k, 1)]                       # g[k]
-        return jnp.maximum(acc, w + gk[0])
-
-    init = jnp.full((block,), NEG, dtype=jnp.float32)
-    o_ref[0, :] = jax.lax.fori_loop(0, band + 1, body, init)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("band", "block", "interpret"))
-def _maxplus_call(prev_pad, g, band: int, block: int, interpret: bool):
-    grid_blocks = (prev_pad.shape[1] - band) // block
-    return pl.pallas_call(
-        functools.partial(_maxplus_kernel, band=band, block=block),
-        grid=(grid_blocks,),
-        in_specs=[
-            pl.BlockSpec(prev_pad.shape, lambda i: (0, 0)),
-            pl.BlockSpec(g.shape, lambda i: (0, 0)),
+def _maxplus_rows_kernel(x_ref, g_ref, o_ref, *, band: int, block: int,
+                         win: int):
+    """o[r, j0 + dj] = max_{0 <= k <= band} x[r, j0 + band + dj - k]
+    + g[r, k] for the (row tile, output block) this program owns.
+
+    The candidate shift k is a lane offset that is not a multiple of the
+    lane tile, which Mosaic cannot load directly.  So the program loads
+    one aligned window of x and the whole g row block, and carries both
+    through the band loop as lane rotations by one: after k steps lane
+    dj of the x carry holds x[j0 + band - k + dj] and lane 0 of the g
+    carry holds g[k].  Shifts are int32 even when the caller traces under
+    x64, as Mosaic's rotate takes nothing wider."""
+    j0 = pl.multiple_of(pl.program_id(1) * block, block)
+    gw = g_ref.shape[1]
+    one, g_back = np.int32(1), np.int32(gw - 1)
+    xr = pltpu.roll(x_ref[:, pl.ds(j0, win)], np.int32(win - band), 1)
+    gr = g_ref[...]
+
+    def body(_, carry):
+        acc, xr, gr = carry
+        acc = jnp.maximum(acc, xr[:, :block] + gr[:, :1])
+        return acc, pltpu.roll(xr, one, 1), pltpu.roll(gr, g_back, 1)
+
+    init = jnp.full((x_ref.shape[0], block), NEG, dtype=jnp.float32)
+    o_ref[...] = jax.lax.fori_loop(np.int32(0), np.int32(band + 1), body,
+                                   (init, xr, gr))[0]
+
+
+@functools.partial(jax.jit, static_argnames=("band", "n_out", "block",
+                                             "interpret"))
+def _maxplus_rows(x, g, band: int, n_out: int, block: int,
+                  interpret: bool):
+    """``out[r, j] = max_{0 <= k <= band} x[r, j + band - k] + g[r, k]``
+    for ``j < n_out``: the one Pallas launch behind every entry point.
+    ``x`` is (B, band + n_out) and ``g`` (B, >= band + 1), float32 with
+    -inf wherever a candidate must not count.  Rows are padded to the
+    sublane tile and widths to the lane tile with -inf, so every block
+    is (8, 128)-aligned; padded rows and cells are sliced off."""
+    B = x.shape[0]
+    rows = _round_up(max(B, 1), ROWS)
+    nb = max(1, -(-n_out // block))                      # cdiv
+    win = _round_up(band + block, LANES)
+    xw = (nb - 1) * block + win
+    gw = _round_up(band + 1, LANES)
+    x_pad = jnp.full((rows, xw), NEG, dtype=jnp.float32)
+    x_pad = x_pad.at[:B, :x.shape[1]].set(x)
+    g_pad = jnp.full((rows, gw), NEG, dtype=jnp.float32)
+    g_pad = g_pad.at[:B, :band + 1].set(g[:, :band + 1])
+    out = pl.pallas_call(
+        functools.partial(_maxplus_rows_kernel, band=band, block=block,
+                          win=win),
+        grid=(rows // ROWS, nb),
+        in_specs=[   # int32 block indices, also under a caller's x64
+            pl.BlockSpec((ROWS, xw), lambda r, i: (r, np.int32(0))),
+            pl.BlockSpec((ROWS, gw), lambda r, i: (r, np.int32(0))),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, grid_blocks * block),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((ROWS, block), lambda r, i: (r, i)),
+        out_shape=jax.ShapeDtypeStruct((rows, nb * block), jnp.float32),
         interpret=interpret,
-    )(prev_pad, g)
+    )(x_pad, g_pad)
+    return out[:B, :n_out]
 
 
 def maxplus_conv(prev, g, band: Optional[int] = None, *,
@@ -89,23 +130,14 @@ def maxplus_conv(prev, g, band: Optional[int] = None, *,
     ``g`` (reward row), both length n+1; returns the length-n+1 float32
     value vector.  ``band=None`` is the dense convolution; a finite band
     is exact under the planner's band contract (``prev`` monotone,
-    ``g`` flat past the band)."""
+    ``g`` flat past the band).  A one-row ``maxplus_conv_batched``."""
     prev = jnp.asarray(prev, dtype=jnp.float32)
     g = jnp.asarray(g, dtype=jnp.float32)
     if prev.ndim != 1 or g.ndim != 1 or prev.shape != g.shape:
         raise ValueError(f"prev/g must be equal-length vectors, got "
                          f"{prev.shape} vs {g.shape}")
-    n = prev.shape[0] - 1
-    b = n if band is None else max(0, min(int(band), n))
-    interpret = resolve_interpret(interpret)
-    nb = max(1, -(-(n + 1) // block))                    # cdiv
-    length = nb * block
-    prev_pad = jnp.full((1, b + length), NEG, dtype=jnp.float32)
-    prev_pad = prev_pad.at[0, b:b + n + 1].set(prev)
-    g_pad = jnp.full((1, max(n + 1, block)), NEG, dtype=jnp.float32)
-    g_pad = g_pad.at[0, :n + 1].set(g)
-    out = _maxplus_call(prev_pad, g_pad, b, block, interpret)
-    return out[0, :n + 1]
+    return maxplus_conv_batched(prev[None], g[None], [band], block=block,
+                                interpret=interpret)[0]
 
 
 def maxplus_conv_np(prev: np.ndarray, g: np.ndarray,
@@ -124,41 +156,6 @@ def maxplus_conv_np(prev: np.ndarray, g: np.ndarray,
 # ---------------------------------------------------------------------------
 # Grid-batched kernel: B independent banded convolutions, one pallas_call
 # ---------------------------------------------------------------------------
-
-
-def _maxplus_batched_kernel(prev_ref, g_ref, o_ref, *, band: int,
-                            block: int):
-    """o[b, dj] = max_k prev_pad[b, j0 + band + dj - k] + g[b, k] for the
-    (batch row, output block) this program owns."""
-    j0 = pl.program_id(1) * block
-
-    def body(k, acc):
-        w = prev_ref[0, pl.ds(j0 + band - k, block)]     # prev[b, j0+dj-k]
-        gk = g_ref[0, pl.ds(k, 1)]                       # g[b, k]
-        return jnp.maximum(acc, w + gk[0])
-
-    init = jnp.full((block,), NEG, dtype=jnp.float32)
-    o_ref[0, :] = jax.lax.fori_loop(0, band + 1, body, init)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("band", "block", "interpret"))
-def _maxplus_batched_call(prev_pad, g, band: int, block: int,
-                          interpret: bool):
-    B = prev_pad.shape[0]
-    grid_blocks = (prev_pad.shape[1] - band) // block
-    return pl.pallas_call(
-        functools.partial(_maxplus_batched_kernel, band=band, block=block),
-        grid=(B, grid_blocks),
-        in_specs=[
-            pl.BlockSpec((1, prev_pad.shape[1]), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, g.shape[1]), lambda b, i: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, grid_blocks * block),
-                                       jnp.float32),
-        interpret=interpret,
-    )(prev_pad, g)
 
 
 def maxplus_conv_batched(prev, g, bands=None, *, block: int = 128,
@@ -186,55 +183,16 @@ def maxplus_conv_batched(prev, g, bands=None, *, block: int = 128,
     if len(bs) != B:
         raise ValueError(f"got {len(bs)} bands for a batch of {B}")
     bmax = int(bs.max()) if B else 0
-    interpret = resolve_interpret(interpret)
-    nb = max(1, -(-n1 // block))                         # cdiv
-    length = nb * block
-    prev_pad = jnp.full((B, bmax + length), NEG, dtype=jnp.float32)
-    prev_pad = prev_pad.at[:, bmax:bmax + n1].set(prev)
+    prev_pad = jnp.pad(prev, ((0, 0), (bmax, 0)), constant_values=NEG)
     ks = np.arange(n1)
     g = jnp.where(jnp.asarray(ks[None, :] > bs[:, None]), NEG, g)
-    g_pad = jnp.full((B, max(n1, block)), NEG, dtype=jnp.float32)
-    g_pad = g_pad.at[:, :n1].set(g)
-    out = _maxplus_batched_call(prev_pad, g_pad, bmax, block, interpret)
-    return out[:, :n1]
+    return _maxplus_rows(prev_pad, g, bmax, n1, block,
+                         resolve_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
 # Scan-compatible chunk kernel: the fused one-program engine's inner step
 # ---------------------------------------------------------------------------
-
-
-def _maxplus_scan_kernel(w_ref, g_ref, o_ref, *, chunk: int, block: int):
-    """o[r, dj] = max_k w[r, j0 + dj + chunk-1 - k] + g[r, k] for the
-    (row, output block) this program owns."""
-    j0 = pl.program_id(1) * block
-
-    def body(k, acc):
-        w = w_ref[0, pl.ds(j0 + chunk - 1 - k, block)]   # w[r, j+K-1-k]
-        gk = g_ref[0, pl.ds(k, 1)]                       # g[r, k]
-        return jnp.maximum(acc, w + gk[0])
-
-    init = jnp.full((block,), NEG, dtype=jnp.float32)
-    o_ref[0, :] = jax.lax.fori_loop(0, chunk, body, init)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "block", "interpret"))
-def _maxplus_scan_call(wins, gs, chunk: int, block: int, interpret: bool):
-    B = wins.shape[0]
-    grid_blocks = (wins.shape[1] - (chunk - 1)) // block
-    return pl.pallas_call(
-        functools.partial(_maxplus_scan_kernel, chunk=chunk, block=block),
-        grid=(B, grid_blocks),
-        in_specs=[
-            pl.BlockSpec((1, wins.shape[1]), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, gs.shape[1]), lambda b, i: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, grid_blocks * block),
-                                       jnp.float32),
-        interpret=interpret,
-    )(wins, gs)
 
 
 def maxplus_scan_chunk(wins, gs, *, block: int = 128,
@@ -267,11 +225,5 @@ def maxplus_scan_chunk(wins, gs, *, block: int = 128,
     if n1 < 1:
         raise ValueError(f"window width {wins.shape[1]} shorter than "
                          f"chunk {K}")
-    interpret = resolve_interpret(interpret)
-    nb = max(1, -(-n1 // block))                         # cdiv
-    wins_pad = jnp.full((B, (K - 1) + nb * block), NEG, dtype=jnp.float32)
-    wins_pad = wins_pad.at[:, :wins.shape[1]].set(wins)
-    gs_pad = jnp.full((B, max(K, block)), NEG, dtype=jnp.float32)
-    gs_pad = gs_pad.at[:, :K].set(gs)
-    out = _maxplus_scan_call(wins_pad, gs_pad, K, block, interpret)
-    return out[:, :n1]
+    return _maxplus_rows(wins, gs, K - 1, n1, block,
+                         resolve_interpret(interpret))

@@ -1,10 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Each op runs the Pallas forward kernel and differentiates through the
-pure-jnp oracle (``ref.py``) via ``jax.custom_vjp`` — standard practice
-for forward-optimized kernels: the backward pass recomputes from the
-oracle, which is bitwise-compatible with the kernel output to float
-tolerance (asserted by tests/test_kernels.py).
+Each op runs the Pallas forward kernel and differentiates through a
+pure-jnp implementation via ``jax.custom_vjp`` — standard practice for
+forward-optimized kernels: the backward pass recomputes from jnp code
+that agrees with the kernel output to float tolerance (asserted by
+tests/test_kernels.py).  Attention recomputes through the flash-style
+custom VJP of ``models.flash_vjp``, so its backward keeps nothing of size
+O(S^2); differentiating the blocked oracle would stack every block's
+probabilities (6 GB a layer at 32 heads x 4096 tokens).  SSD and
+RMSNorm differentiate their oracles in ``ref.py``.
 
 ``interpret`` resolution lives in ``pallas_config.resolve_interpret``: the
 kernels compile on TPU (Mosaic) and interpret everywhere else, with
@@ -22,6 +26,7 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.models.flash_vjp import flash_attention_jnp
 
 
 # ---------------------------------------------------------------------------
@@ -44,9 +49,8 @@ def _fa_fwd(q, k, v, causal, window, softcap, q_offset):
 def _fa_bwd(causal, window, softcap, q_offset, res, g):
     q, k, v = res
     _, vjp = jax.vjp(
-        lambda q, k, v: ref.flash_attention(
-            q, k, v, causal=causal, window=window, softcap=softcap,
-            q_offset=q_offset), q, k, v)
+        lambda q, k, v: flash_attention_jnp(q, k, v, causal, window,
+                                            softcap, q_offset), q, k, v)
     return vjp(g)
 
 

@@ -1,12 +1,13 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the ground truth the kernel tests ``assert_allclose`` against
-(and the backward functions for the kernels' custom VJPs).  They
+(and the backward functions of the SSD and RMSNorm custom VJPs).  They
 intentionally share code with the model's own jnp paths so that switching
 ``kernel="jnp" -> "pallas"`` is a pure performance change.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -17,12 +18,13 @@ from repro.models.ssm import ssd_chunked
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, q_offset: int = 0) -> jnp.ndarray:
     """Oracle attention: blocked online-softmax for long sequences,
-    direct softmax for short ones (they agree to float tolerance)."""
-    if q.shape[1] > 1024:
-        return blocked_attention(q, k, v, causal=causal, window=window,
-                                 softcap=softcap, q_offset=q_offset)
-    return simple_attention(q, k, v, causal=causal, window=window,
-                            softcap=softcap, q_offset=q_offset)
+    direct softmax for short ones (they agree to float tolerance).  Its
+    matmuls run at full float32 precision, which a TPU otherwise replaces
+    with bfloat16 passes."""
+    attend = blocked_attention if q.shape[1] > 1024 else simple_attention
+    with jax.default_matmul_precision("highest"):
+        return attend(q, k, v, causal=causal, window=window,
+                      softcap=softcap, q_offset=q_offset)
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, init_state=None):

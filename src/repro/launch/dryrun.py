@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"     # never takes an attached chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run driver.
@@ -14,8 +15,9 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b \
         --shape train_4k [--multi-pod] [--json out.json]
 
-The two XLA_FLAGS lines above MUST stay first: jax locks the device count
-on first initialization.
+The environment lines above MUST stay first: jax fixes its platform and
+device count on first initialization.  The run is pinned to the CPU, so a
+sweep's children never take a chip that another process holds.
 """
 import argparse
 import json

@@ -190,11 +190,18 @@ def _moe_local(cfg, xt, router_w, w_in, w_gate, w_out, model_axis: str,
 
 
 def moe_apply_shardmap(p: dict, cfg, x: jnp.ndarray):
-    """Expert-parallel MoE via shard_map (see SHARD_MAP above)."""
-    from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    """Expert-parallel MoE via shard_map (see SHARD_MAP above).
+
+    The rest of the model is written for GSPMD propagation, so the
+    shard_map runs over the mesh with Auto axis types: an Explicit mesh
+    would type the output as data-sharded, and the backward of every
+    later replicated-weight matmul would then contract over a sharded
+    dimension, which explicit sharding refuses as ambiguous."""
+    from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
     mesh, data_axes = SHARD_MAP
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     m = cfg.moe
     model_axis = "model"
     n_shards = mesh.shape[model_axis]
@@ -214,13 +221,12 @@ def moe_apply_shardmap(p: dict, cfg, x: jnp.ndarray):
         ce_sum = jax.lax.psum(ce_sum, da)
         return y.reshape(x.shape), me_sum, ce_sum
 
-    y, me_sum, ce_sum = shard_map(
+    y, me_sum, ce_sum = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(da, None, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   P(model_axis, None, None)),
         out_specs=(P(da, None, None), P(None), P(None)),
-        check_rep=False,
     )(x, p["router"], p["w_in"], p["w_gate"], p["w_out"])
 
     T_global = B * S

@@ -196,3 +196,54 @@ def test_drop_rank_hosting_anothers_replica(state):
     # host 2 earlier -> nothing left for rank 1
     store.drop_rank("t", 1)
     assert store.get("t", 1) is None
+
+
+# ---- bf16 state through both tiers (full-width param_dtype) ----------------
+
+
+def _bits_equal(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert np.array_equal(x.reshape(-1).view(np.uint8),
+                              y.reshape(-1).view(np.uint8))
+
+
+@pytest.fixture
+def bf16_train_state():
+    """A train state with bf16 params and f32 master/moments: the layout
+    of a config at its published ``param_dtype``."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models.model import build_model
+    from repro.optim import AdamW, constant
+    from repro.train.state import init_train_state
+    cfg = dataclasses.replace(get_arch("qwen3-4b").reduced(),
+                              param_dtype="bfloat16")
+    state = init_train_state(build_model(cfg), AdamW(lr=constant(1e-3)),
+                             jax.random.PRNGKey(0))
+    assert jax.tree.leaves(state.params)[0].dtype == jnp.bfloat16
+    return state
+
+
+def test_bf16_state_roundtrips_persistent_bitwise(tmp_path, bf16_train_state):
+    persistent.save(str(tmp_path), 4, bf16_train_state)
+    _bits_equal(persistent.restore(str(tmp_path), bf16_train_state),
+                bf16_train_state)
+
+
+def test_bf16_state_restores_from_both_tiers_bitwise(tmp_path,
+                                                    bf16_train_state):
+    mgr = CheckpointManager(str(tmp_path), n_ranks=2, persist_every=2,
+                            task="bf16")
+    mgr.save(rank=0, step=2, state=bf16_train_state)
+    got, step, src = mgr.restore(0, bf16_train_state)
+    assert (step, src) == (2, "inmemory_local")
+    _bits_equal(got, bf16_train_state)
+    mgr.drop_rank(0)
+    mgr.drop_rank(mgr.store.neighbor(0))
+    got, step, src = mgr.restore(0, bf16_train_state)
+    assert (step, src) == (2, "persistent")
+    _bits_equal(got, bf16_train_state)

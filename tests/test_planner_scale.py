@@ -647,8 +647,8 @@ def test_fused_same_signature_churn_no_retrace():
             assert planner_mod._FUSED_PROGRAMS[sig] is prog
     assert dispatches == len(states)
     assert prog.calls >= len(states)
-    # ONE trace for the whole walk (-1 only if this jax cannot report it)
-    assert prog.traces() in (-1, 1)
+    # ONE trace for the whole walk
+    assert prog.traces() == 1
 
 
 def test_fused_engine_pallas_backend_matches_reference():
