@@ -33,7 +33,7 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 BENCHES = ["detection", "costmodel", "maxplus", "planner_scale",
            "cluster_sim", "serving_slo", "transition", "frontier",
            "throughput", "waf_multitask", "traces", "ablation",
-           "roofline", "chaos", "controlplane"]
+           "chaos", "controlplane"]
 QUICK_BENCHES = ["detection", "costmodel", "maxplus", "planner_scale",
                  "cluster_sim", "serving_slo", "transition", "frontier",
                  "chaos", "controlplane"]

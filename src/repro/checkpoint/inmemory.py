@@ -16,10 +16,22 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import obs
+
+
+def _leaf_to_host(x: Any) -> np.ndarray:
+    """``np.array(x)`` in its two steps: the device-to-host transfer into
+    the runtime's host buffer, then the copy into an array the snapshot
+    owns."""
+    with obs.span("ckpt.d2h"):
+        host = np.asarray(x)
+    with obs.span("ckpt.host_copy"):
+        return np.array(host)
+
 
 def _snapshot(tree: Any) -> Any:
     """Copy a pytree to host memory (numpy)."""
-    return jax.tree.map(lambda x: np.array(x), tree)
+    return jax.tree.map(_leaf_to_host, tree)
 
 
 class InMemoryStore:
@@ -41,8 +53,11 @@ class InMemoryStore:
     def put(self, task: str, rank: int, step: int, tree: Any) -> Any:
         """Store a host snapshot of ``tree``; returns the snapshot."""
         snap = _snapshot(tree)
-        self._local[(task, rank)] = (step, snap)
-        self._replica[(task, self.neighbor(rank))] = (step, snap)
+        # the previous snapshot's host arrays are freed here, with the
+        # last reference to them
+        with obs.span("ckpt.release"):
+            self._local[(task, rank)] = (step, snap)
+            self._replica[(task, self.neighbor(rank))] = (step, snap)
         return snap
 
     def drop_rank(self, task: str, rank: int) -> None:

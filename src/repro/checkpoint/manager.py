@@ -15,6 +15,9 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
+import jax
+
+from repro import obs
 from repro.checkpoint import inmemory, persistent
 
 
@@ -37,9 +40,12 @@ class CheckpointManager:
         every ``persist_every`` steps (synchronous here; the simulator
         models the asynchrony).  The persistent tier writes the host
         snapshot, so the state crosses from the device once."""
-        snap = self.store.put(self.task, rank, step, state)
-        if step % self.persist_every == 0:
-            persistent.save(self.directory, step, snap)
+        with obs.span("ckpt.save", step=step) as sp:
+            snap = self.store.put(self.task, rank, step, state)
+            sp.attrs["bytes"] = sum(x.nbytes for x in jax.tree.leaves(snap))
+            if step % self.persist_every == 0:
+                with obs.span("ckpt.persist", step=step):
+                    persistent.save(self.directory, step, snap)
 
     # ---- restore path (nearest principle) ---------------------------------
 
@@ -52,6 +58,12 @@ class CheckpointManager:
         exists — the nearest source (the caller knows its peers; Unicron's
         coordinator passes it when replication is possible).
         """
+        with obs.span("ckpt.restore") as sp:
+            got = self._restore(rank, like, dp_peer_state, peer_step)
+            sp.attrs["tier"] = got[2]
+        return got
+
+    def _restore(self, rank, like, dp_peer_state, peer_step):
         if dp_peer_state is not None:
             return dp_peer_state, int(peer_step or 0), "dp_replica"
         hit = self.store.get(self.task, rank)

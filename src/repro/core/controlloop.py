@@ -68,6 +68,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Set, Tuple
 
+from repro import obs
 from repro.core.agent import UnicronAgent
 from repro.core.cluster import Cluster
 from repro.core.coordinator import UnicronCoordinator
@@ -118,7 +119,7 @@ class ControlLoop:
         # store — the event-driven guarantee)
         self.tick_stats = {"ticks": 0, "prefix_scans": 0,
                            "drain_sorts": 0, "queue_reads": 0,
-                           "records_consumed": 0, "gc_runs": 0}
+                           "gc_runs": 0}
         # queue-cursor drains when the store offers append queues,
         # scan+sort fallback otherwise (LegacyKVStore)
         self._queued = callable(getattr(self.kv, "queue_slice", None))
@@ -204,7 +205,6 @@ class ControlLoop:
                     continue
                 self._consume(key, now)
                 out.append((key, rec))
-            self.tick_stats["records_consumed"] += len(out)
             return out
 
         cursor = self._cursors[family]
@@ -240,27 +240,38 @@ class ControlLoop:
         if out:
             self.tick_stats["drain_sorts"] += 1
             out.sort(key=lambda kr: kr[0])
-        self.tick_stats["records_consumed"] += len(out)
         return out
+
+    def _drain(self, family: str, now: float) -> List[Tuple[str, Dict]]:
+        with obs.span("ctrl.drain", family=family):
+            return self._due_records(family, now)
 
     # ---- one tick of the loop ---------------------------------------------
 
     def tick(self, now: float) -> List[LoopEvent]:
-        self.tick_stats["ticks"] += 1
-        out: List[LoopEvent] = []
-        out += self._expire_heartbeats(now)
-        out += self._drain_error_reports(now)
-        out += self._drain_task_reports(now)
-        out += self._drain_launch_requests(now)
-        out += self._rejoin_repaired(now)
-        out += self._rejoin_reappeared(now)
-        self._gc_markers(now)
-        self.events += out
+        """One pass of the loop in a ``ctrl.tick`` span.  Its children:
+        lease expiry (``ctrl.expire``), each queue drain (``ctrl.drain``),
+        each event's handling (``ctrl.handle``, with its node and kind)
+        and the marker GC (``ctrl.gc``)."""
+        with obs.span("ctrl.tick", now=now):
+            self.tick_stats["ticks"] += 1
+            out: List[LoopEvent] = []
+            out += self._expire_heartbeats(now)
+            out += self._drain_error_reports(now)
+            out += self._drain_task_reports(now)
+            out += self._drain_launch_requests(now)
+            out += self._rejoin_repaired(now)
+            out += self._rejoin_reappeared(now)
+            with obs.span("ctrl.gc"):
+                self._gc_markers(now)
+            self.events += out
         return out
 
     def _expire_heartbeats(self, now: float) -> List[LoopEvent]:
         out = []
-        for key in self.kv.expire(now):
+        with obs.span("ctrl.expire"):
+            expired = self.kv.expire(now)
+        for key in expired:
             if not key.startswith("/nodes/"):
                 continue
             node = int(key.split("/")[2])
@@ -269,7 +280,7 @@ class ControlLoop:
 
     def _drain_error_reports(self, now: float) -> List[LoopEvent]:
         out = []
-        for key, rec in self._due_records(ERRORS_FAMILY, now):
+        for key, rec in self._drain(ERRORS_FAMILY, now):
             out.append(self._handle(now, rec["node"],
                                     ErrorKind(rec["kind"])))
         return out
@@ -285,7 +296,7 @@ class ControlLoop:
         set, still-queued reports refer to indices that no longer name
         the same task and are consumed without firing (their workers
         re-report against the new epoch if the task is genuinely done)."""
-        due = self._due_records(FINISHED_FAMILY, now)
+        due = self._drain(FINISHED_FAMILY, now)
         if not due:
             return []
         epoch = self.kv.get(PLAN_EPOCH_KEY, 0)
@@ -297,7 +308,8 @@ class ControlLoop:
         out = []
         for idx in sorted(done, reverse=True):
             if 0 <= idx < len(self.coord.entries):
-                out.append(self._task_finished_event(now, idx))
+                with obs.span("ctrl.handle", node=-1, kind="task_finished"):
+                    out.append(self._task_finished_event(now, idx))
         return out
 
     def _drain_launch_requests(self, now: float) -> List[LoopEvent]:
@@ -307,7 +319,7 @@ class ControlLoop:
         ``task_finished`` — a request computed against a superseded plan
         state is consumed without firing (its submitter re-announces
         against the new epoch if the launch still stands)."""
-        due = self._due_records(LAUNCH_FAMILY, now)
+        due = self._drain(LAUNCH_FAMILY, now)
         if not due:
             return []
         epoch = self.kv.get(PLAN_EPOCH_KEY, 0)
@@ -318,34 +330,37 @@ class ControlLoop:
             pending.setdefault(rec["task"], rec)
         out = []
         for task, rec in pending.items():
-            plan = self.coord.task_launched(
-                task, self.cluster.healthy_workers(),
-                avg_iter_s=rec.get("avg_iter_s", 30.0))
-            self.cluster.assign(list(plan.assignment))
-            out.append(self._stamped(LoopEvent(
-                now, rec["node"], None, Action.RESUME, plan.assignment,
-                self.coord.plan_stats.last_dispatch_s)))
+            with obs.span("ctrl.handle", node=rec["node"],
+                          kind="task_launched"):
+                plan = self.coord.task_launched(
+                    task, self.cluster.healthy_workers(),
+                    avg_iter_s=rec.get("avg_iter_s", 30.0))
+                self.cluster.assign(list(plan.assignment))
+                out.append(self._stamped(LoopEvent(
+                    now, rec["node"], None, Action.RESUME, plan.assignment,
+                    self.coord.plan_stats.last_dispatch_s)))
         return out
 
     def _rejoin_repaired(self, now: float) -> List[LoopEvent]:
         out = []
         for node in self.cluster.repair_due(now):
-            self.cluster.recover_node(node.node_id)
-            if node.node_id in self.agents:
-                self.agents[node.node_id].alive = True
-            # a repaired node is a fresh join, not a reappearance:
-            # drop any pending lost-node snapshot so the restore path
-            # cannot fire once its heartbeats resume
-            self.kv.delete(f"{LOST_PREFIX}{node.node_id}")
-            self._lost_nodes.discard(node.node_id)
-            plan = self.coord.reconfigure(
-                self.cluster.healthy_workers(),
-                trigger=Trigger.NODE_JOIN)
-            self.cluster.assign(list(plan.assignment))
-            out.append(self._stamped(LoopEvent(
-                now, node.node_id, ErrorKind.LOST_CONNECTION,
-                Action.RESUME, plan.assignment,
-                self.coord.plan_stats.last_dispatch_s)))
+            with obs.span("ctrl.handle", node=node.node_id, kind="repaired"):
+                self.cluster.recover_node(node.node_id)
+                if node.node_id in self.agents:
+                    self.agents[node.node_id].alive = True
+                # a repaired node is a fresh join, not a reappearance:
+                # drop any pending lost-node snapshot so the restore path
+                # cannot fire once its heartbeats resume
+                self.kv.delete(f"{LOST_PREFIX}{node.node_id}")
+                self._lost_nodes.discard(node.node_id)
+                plan = self.coord.reconfigure(
+                    self.cluster.healthy_workers(),
+                    trigger=Trigger.NODE_JOIN)
+                self.cluster.assign(list(plan.assignment))
+                out.append(self._stamped(LoopEvent(
+                    now, node.node_id, ErrorKind.LOST_CONNECTION,
+                    Action.RESUME, plan.assignment,
+                    self.coord.plan_stats.last_dispatch_s)))
         return out
 
     def _rejoin_reappeared(self, now: float) -> List[LoopEvent]:
@@ -371,27 +386,29 @@ class ControlLoop:
             hb = self.kv.get(f"/nodes/{node}/alive")
             if hb is None or float(hb) <= saved["drained_at"]:
                 continue                       # still silent
-            self.kv.delete(key)
-            self._lost_nodes.discard(node)
-            self.cluster.recover_node(node)
-            if node in self.agents:
-                self.agents[node].alive = True
-            restorable = (
-                saved["epoch"] == self.coord.plan_epoch
-                and len(saved["assignment"]) == len(self.coord.entries)
-                and self.cluster.healthy_workers() == saved["healthy_workers"])
-            if restorable:
-                self.coord.restore_assignment(saved["assignment"])
-                plan, plan_s = tuple(saved["assignment"]), None
-            else:
-                p = self.coord.reconfigure(self.cluster.healthy_workers(),
-                                           trigger=Trigger.NODE_JOIN)
-                plan = p.assignment
-                plan_s = self.coord.plan_stats.last_dispatch_s
-            self.cluster.assign(list(plan))
-            out.append(self._stamped(LoopEvent(
-                now, node, ErrorKind.LOST_CONNECTION, Action.RESUME,
-                plan, plan_s)))
+            with obs.span("ctrl.handle", node=node, kind="reappeared"):
+                self.kv.delete(key)
+                self._lost_nodes.discard(node)
+                self.cluster.recover_node(node)
+                if node in self.agents:
+                    self.agents[node].alive = True
+                restorable = (
+                    saved["epoch"] == self.coord.plan_epoch
+                    and len(saved["assignment"]) == len(self.coord.entries)
+                    and self.cluster.healthy_workers()
+                    == saved["healthy_workers"])
+                if restorable:
+                    self.coord.restore_assignment(saved["assignment"])
+                    plan, plan_s = tuple(saved["assignment"]), None
+                else:
+                    p = self.coord.reconfigure(self.cluster.healthy_workers(),
+                                               trigger=Trigger.NODE_JOIN)
+                    plan = p.assignment
+                    plan_s = self.coord.plan_stats.last_dispatch_s
+                self.cluster.assign(list(plan))
+                out.append(self._stamped(LoopEvent(
+                    now, node, ErrorKind.LOST_CONNECTION, Action.RESUME,
+                    plan, plan_s)))
         return out
 
     # ---- decision path -----------------------------------------------------
@@ -421,17 +438,19 @@ class ControlLoop:
         # case ids carry the wall clock so they stay unique across a
         # coordinator crash (the per-loop sequence restarts at 0)
         case_id = f"{node}:{kind.value}:{now:.3f}:{self._case_seq}"
-        decision = self.coord.on_error(case_id, kind)
-        plan, plan_s = None, None
-        if decision.action is Action.RECONFIGURE \
-                and self.cluster.nodes[node].healthy:
-            # the healthy guard makes duplicate SEV1s on an
-            # already-drained node (e.g. a delayed heartbeat re-creating
-            # then re-expiring a lease) a no-op instead of a double drain
-            plan, plan_s = self._drain_and_replan(now, node, kind)
-        self.coord.close_case(case_id)
-        return self._stamped(LoopEvent(now, node, kind, decision.action,
-                                       plan, plan_s))
+        with obs.span("ctrl.handle", node=node, kind=kind.value,
+                      case=case_id):
+            decision = self.coord.on_error(case_id, kind)
+            plan, plan_s = None, None
+            if decision.action is Action.RECONFIGURE \
+                    and self.cluster.nodes[node].healthy:
+                # the healthy guard makes duplicate SEV1s on an
+                # already-drained node (e.g. a delayed heartbeat re-creating
+                # then re-expiring a lease) a no-op instead of a double drain
+                plan, plan_s = self._drain_and_replan(now, node, kind)
+            self.coord.close_case(case_id)
+            return self._stamped(LoopEvent(now, node, kind, decision.action,
+                                           plan, plan_s))
 
     # ---- task churn entry points (Figure 7 triggers 5 and 6) --------------
 

@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core import planner, waf as waf_mod
 from repro.core.costmodel import Hardware
 from repro.core.detection import ErrorKind, Severity
@@ -68,11 +69,9 @@ class PlanStats:
     another coordinator's work lands on whichever handle reads it first,
     so sums over all coordinators remain exact."""
     table_rebuilds: int = 0
-    table_rebuild_s: float = 0.0       # cumulative
-    last_rebuild_s: float = 0.0
+    table_rebuild_s: float = 0.0       # cumulative ``plan.rebuild`` spans
     lookup_hits: int = 0
     fresh_solves: int = 0
-    fresh_solve_s: float = 0.0         # cumulative
     last_dispatch_s: float = 0.0       # latency of the last plan_for()
     task_launches: int = 0
     task_finishes: int = 0
@@ -307,45 +306,44 @@ class UnicronCoordinator:
         d_run = self._d_running(sum(assignment))
         w = self.workers_per_node
         n_budget = (self.n_cluster + w) if self.n_cluster else None
-        t0 = time.perf_counter()
-        tasks = [e.task for e in self.entries]
-        if self.plan_cache is not None:
-            self._table = self.plan_cache.table(tasks, assignment, self.hw,
-                                                d_run, self.d_transition,
-                                                workers_per_fault=w,
-                                                n_budget=n_budget,
-                                                engine=self.plan_engine,
-                                                task_ids=self._tids)
-            self._adopt_table(self._table, fresh=False)
-        else:
-            self._table = PlanTable(tasks, assignment, self.hw, d_run,
-                                    self.d_transition,
-                                    workers_per_fault=w,
-                                    n_budget=n_budget,
-                                    engine=self.plan_engine)
-            self._adopt_table(self._table, fresh=True)
-        if self.prebuild_scenarios:
-            self._table.rebuild_values()
-        self._sync_batch_stats()
-        dt = time.perf_counter() - t0
+        with obs.span("plan.rebuild") as rebuild:
+            tasks = [e.task for e in self.entries]
+            with obs.span("plan.table"):
+                if self.plan_cache is not None:
+                    self._table = self.plan_cache.table(
+                        tasks, assignment, self.hw, d_run, self.d_transition,
+                        workers_per_fault=w, n_budget=n_budget,
+                        engine=self.plan_engine, task_ids=self._tids)
+                else:
+                    self._table = PlanTable(tasks, assignment, self.hw,
+                                            d_run, self.d_transition,
+                                            workers_per_fault=w,
+                                            n_budget=n_budget,
+                                            engine=self.plan_engine)
+            self._adopt_table(self._table,
+                              fresh=self.plan_cache is None)
+            if self.prebuild_scenarios:
+                self._table.rebuild_values()
+            self._sync_batch_stats()
         self.plan_stats.table_rebuilds += 1
-        self.plan_stats.table_rebuild_s += dt
-        self.plan_stats.last_rebuild_s = dt
+        self.plan_stats.table_rebuild_s += rebuild.seconds
 
     def plan_for(self, n_workers: int, faulted_task: Optional[int],
                  lookup_key: Optional[str] = None) -> Tuple[Plan, bool]:
         """Returns (plan, was_lookup_hit)."""
-        t0 = time.perf_counter()
-        if lookup_key and self._table:
-            hit = self._table.lookup(lookup_key)
-            self._sync_batch_stats()
-            if hit is not None:
+        with obs.span("plan.dispatch", hit=False) as sp:
+            plan = None
+            if lookup_key and self._table:
+                plan = self._table.lookup(lookup_key)
+                self._sync_batch_stats()
+            hit = plan is not None
+            if hit:
                 self.plan_stats.lookup_hits += 1
-                self.plan_stats.last_dispatch_s = time.perf_counter() - t0
-                return hit, True
-        plan = self._fresh_plan(n_workers, faulted_task)
-        self.plan_stats.last_dispatch_s = time.perf_counter() - t0
-        return plan, False
+                sp.attrs["hit"] = True
+            else:
+                plan = self._fresh_plan(n_workers, faulted_task)
+        self.plan_stats.last_dispatch_s = sp.seconds
+        return plan, hit
 
     # ---- error handling ----------------------------------------------------
 
@@ -398,15 +396,14 @@ class UnicronCoordinator:
     def _fresh_plan(self, n_workers_now: int,
                     faulted_task: Optional[int] = None) -> Plan:
         """Single fresh-dispatch path: memoized ``solve_fast`` under a
-        plan cache, plain ``solve`` otherwise, with solve-time stats."""
-        t0 = time.perf_counter()
-        inp = self._plan_input(n_workers_now, faulted_task)
-        if self.plan_cache is not None:
-            plan = self.plan_cache.solve(inp, self.hw)
-        else:
-            plan = planner.solve(inp, self.hw)
+        plan cache, plain ``solve`` otherwise, in a ``plan.solve`` span."""
+        with obs.span("plan.solve"):
+            inp = self._plan_input(n_workers_now, faulted_task)
+            if self.plan_cache is not None:
+                plan = self.plan_cache.solve(inp, self.hw)
+            else:
+                plan = planner.solve(inp, self.hw)
         self.plan_stats.fresh_solves += 1
-        self.plan_stats.fresh_solve_s += time.perf_counter() - t0
         return plan
 
     def task_finished(self, task_index: int, n_workers_now: int) -> Plan:

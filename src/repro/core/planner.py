@@ -165,6 +165,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import waf as waf_mod
 from repro.core.costmodel import Hardware
 from repro.core.waf import Task
@@ -890,8 +891,11 @@ class _FusedProgram:
     def __call__(self, g_unf: np.ndarray, g_f: np.ndarray,
                  limits: np.ndarray):
         with self._jax.enable_x64(True):  # trace AND dispatch in f64
-            vals, js, totals = self._fn(g_unf, g_f, limits)
-            out = (np.asarray(vals), np.asarray(js), np.asarray(totals))
+            with obs.span("plan.program.wait"):
+                got = self._jax.block_until_ready(
+                    self._fn(g_unf, g_f, limits))
+            with obs.span("plan.fetch"):
+                out = tuple(np.asarray(x) for x in got)
         self.calls += 1
         return out
 
@@ -912,8 +916,9 @@ def _fused_program(m: int, n_max: int, bands_unf: Tuple[int, ...],
         if prog is not None:
             _FUSED_PROGRAMS.move_to_end(key)
             return prog
-    prog = _FusedProgram(_FusedSchedule(m, n_max, bands_unf, bands_f),
-                         backend)
+    with obs.span("plan.schedule", m=m):
+        sched = _FusedSchedule(m, n_max, bands_unf, bands_f)
+    prog = _FusedProgram(sched, backend)
     with _fused_lock:
         got = _FUSED_PROGRAMS.setdefault(key, prog)
         _FUSED_PROGRAMS.move_to_end(key)
@@ -1681,32 +1686,35 @@ class PlanTable:
         hit at the ``PlannerCache.table`` level."""
         m = len(self.tasks)
         prog = _fused_program(*self._fused_signature())
-        g_unf = np.stack([np.asarray(self._row(i), dtype=float)
-                          for i in range(m)])
-        g_f = np.stack([np.asarray(self._row(i, faulted=True),
-                                   dtype=float) for i in range(m)])
+        with obs.span("plan.rows"):
+            g_unf = np.stack([np.asarray(self._row(i), dtype=float)
+                              for i in range(m)])
+            g_f = np.stack([np.asarray(self._row(i, faulted=True),
+                                       dtype=float) for i in range(m)])
         limits = np.asarray([self._n_fault] * m + [self._n_now] * m
                             + [self._n_join], dtype=np.int32)
-        vals, js, totals = prog(g_unf, g_f, limits)
+        with obs.span("plan.program"):
+            vals, js, totals = prog(g_unf, g_f, limits)
         self.batch_stats["device_dispatches"] += 1
         sched = prog.sched
-        for node, si in sched.v_slot.items():
-            self._V[node] = vals[si]
-        self._comp_root()
-        for node, si in sched.c_slot.items():
-            self._Comp.setdefault(node, vals[si])
-        self._sat_memo.update(sched.sat_map)
-        self._csat.update(sched.csat_map)
-        self._csibs.update(sched.csibs_map)
-        for ti in range(m):
-            self._scen.setdefault(
-                f"fault:{ti}", (vals[sched.fault_slot[ti]],
-                                int(js[ti]), float(totals[ti])))
-            self._scen.setdefault(
-                f"finish:{ti}", (self._Comp[(ti, ti + 1)],
-                                 int(js[m + ti]), float(totals[m + ti])))
-        self._scen.setdefault("join:1", (self._V[(0, m)], int(js[2 * m]),
-                                         float(totals[2 * m])))
+        with obs.span("plan.unpack"):
+            for node, si in sched.v_slot.items():
+                self._V[node] = vals[si]
+            self._comp_root()
+            for node, si in sched.c_slot.items():
+                self._Comp.setdefault(node, vals[si])
+            self._sat_memo.update(sched.sat_map)
+            self._csat.update(sched.csat_map)
+            self._csibs.update(sched.csibs_map)
+            for ti in range(m):
+                self._scen.setdefault(
+                    f"fault:{ti}", (vals[sched.fault_slot[ti]],
+                                    int(js[ti]), float(totals[ti])))
+                self._scen.setdefault(
+                    f"finish:{ti}", (self._Comp[(ti, ti + 1)],
+                                     int(js[m + ti]), float(totals[m + ti])))
+            self._scen.setdefault("join:1", (self._V[(0, m)], int(js[2 * m]),
+                                             float(totals[2 * m])))
         self._tree_built = True
         self._values_built = True
 
@@ -1818,44 +1826,45 @@ class PlanTable:
     def _assemble_batched(self, key: str) -> Optional[Plan]:
         """Materialize one scenario's Plan: value vectors from the batched
         store, then the lazy argmax traceback for just this key."""
-        m = len(self.tasks)
-        if key == "join:1":
-            entry = self._scen_entry(key)
+        with obs.span("plan.traceback", key=key):
+            m = len(self.tasks)
+            if key == "join:1":
+                entry = self._scen_entry(key)
+                vec, j, total = entry
+                self.batch_stats["tracebacks"] += 1
+                assign = [0] * m
+                self._walk_span(0, m, j, assign)
+                return Plan(tuple(assign), total,
+                            self._cwaf(self.tasks, assign))
+            parsed = self._parse_leaf_key(key)
+            if parsed is None:
+                return None
+            kind, ti = parsed
+            sibs, Cs = self._chain_batched(ti)
+            entry = self._scen.get(key)
+            if entry is None:
+                if kind == "finish":
+                    entry = self._total_entry(Cs[-1], self._n_now)
+                else:
+                    entry = self._total_entry(self._fault_combined(ti, Cs[-1]),
+                                              self._n_fault)
+                self._scen[key] = entry
             vec, j, total = entry
             self.batch_stats["tracebacks"] += 1
+            # the argmax walks descend every sibling subtree, so build them
+            # (level-launched; usually warm) even when the chain was cached
+            self._ensure_chain_spans(ti)
             assign = [0] * m
-            self._walk_span(0, m, j, assign)
-            return Plan(tuple(assign), total,
-                        self._cwaf(self.tasks, assign))
-        parsed = self._parse_leaf_key(key)
-        if parsed is None:
-            return None
-        kind, ti = parsed
-        sibs, Cs = self._chain_batched(ti)
-        entry = self._scen.get(key)
-        if entry is None:
-            if kind == "finish":
-                entry = self._total_entry(Cs[-1], self._n_now)
-            else:
-                entry = self._total_entry(self._fault_combined(ti, Cs[-1]),
-                                          self._n_fault)
-            self._scen[key] = entry
-        vec, j, total = entry
-        self.batch_stats["tracebacks"] += 1
-        # the argmax walks descend every sibling subtree, so build them
-        # (level-launched; usually warm) even when the chain was cached
-        self._ensure_chain_spans(ti)
-        assign = [0] * m
-        if kind == "fault":
-            k = _argmax_at(Cs[-1], self._row(ti, faulted=True), j)
-            assign[ti] = k
-            self._walk_compl(sibs, Cs, j - k, assign)
-            return Plan(tuple(assign), total,
-                        self._cwaf(self.tasks, assign))
-        self._walk_compl(sibs, Cs, j, assign)
-        del assign[ti]
-        rem = self.tasks[:ti] + self.tasks[ti + 1:]
-        return Plan(tuple(assign), total, self._cwaf(rem, assign))
+            if kind == "fault":
+                k = _argmax_at(Cs[-1], self._row(ti, faulted=True), j)
+                assign[ti] = k
+                self._walk_compl(sibs, Cs, j - k, assign)
+                return Plan(tuple(assign), total,
+                            self._cwaf(self.tasks, assign))
+            self._walk_compl(sibs, Cs, j, assign)
+            del assign[ti]
+            rem = self.tasks[:ti] + self.tasks[ti + 1:]
+            return Plan(tuple(assign), total, self._cwaf(rem, assign))
 
     def rebuild_values(self) -> Dict[str, float]:
         """Whole-table value rebuild: every scenario's value vector and

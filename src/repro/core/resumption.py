@@ -26,6 +26,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 
+from repro import obs
 from repro.train.step import accumulate
 
 
@@ -109,30 +110,37 @@ def run_iteration_with_failure(grad_fn: Callable, params,
     it = MicroBatchIteration(n_ranks=n_ranks, n_micro=n_micro)
     acc: Dict[int, Optional[dict]] = {r: None for r in range(n_ranks)}
 
+    def grads(rank: int, mb: int, redone: bool) -> None:
+        # the span times the host's dispatch of an asynchronous grad_fn
+        with obs.span("sev2.grads", rank=rank, mb=mb, redone=redone):
+            g, _ = grad_fn(params, microbatch_of(mb))
+            acc[rank] = accumulate(acc[rank], g)
+        it.complete(rank, mb)
+
     # 1) ranks run until the failure point
+    lost: List[int] = []
     if fail_rank is not None:
         for mb in it.owners[fail_rank][:fail_after_mb]:
-            g, _ = grad_fn(params, microbatch_of(mb))
-            acc[fail_rank] = accumulate(acc[fail_rank], g)
-            it.complete(fail_rank, mb)
-        # 2) failure: pause, re-establish comms, redistribute (Eq. 7)
+            grads(fail_rank, mb, False)
+        # 2) failure: pause, re-establish comms, redistribute (Eq. 7);
+        # what the rank had done is lost with its accumulator
+        lost = list(it.done[fail_rank])
         it.fail_rank(fail_rank)
-        acc[fail_rank] = None        # accumulator lost with the rank
+        acc[fail_rank] = None
 
     # 3) all surviving ranks finish their (possibly grown) assignments
     for rank in it.live_ranks():
         for mb in it.pending(rank):
-            g, _ = grad_fn(params, microbatch_of(mb))
-            acc[rank] = accumulate(acc[rank], g)
-            it.complete(rank, mb)
+            grads(rank, mb, mb in lost)
     assert it.all_done()
 
     # 4) all-reduce over live ranks
-    total = None
-    for rank in it.live_ranks():
-        if acc[rank] is not None:
-            total = accumulate(total, acc[rank]) if total is not None \
-                else acc[rank]
+    with obs.span("sev2.allreduce"):
+        total = None
+        for rank in it.live_ranks():
+            if acc[rank] is not None:
+                total = accumulate(total, acc[rank]) if total is not None \
+                    else acc[rank]
     return total, n_micro
 
 

@@ -17,12 +17,12 @@ from __future__ import annotations
 import argparse
 import os
 import tempfile
-import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch
 from repro.configs.base import ArchConfig
@@ -82,8 +82,12 @@ class ManagedJob:
         return self.step_fn
 
     def step(self, state: TrainState, step: int) -> Tuple[TrainState, Dict]:
-        """One fault-free fused iteration; ``state`` is donated."""
-        return self.step_fn(state, self.batch(step))
+        """One fault-free fused iteration; ``state`` is donated.  Returns
+        once the step is enqueued."""
+        with obs.span("loop.batch", step=step):
+            batch = self.batch(step)
+        with obs.span("loop.dispatch", step=step):
+            return self.step_fn(state, batch)
 
     def iteration_grads(self, params, step: int,
                         fail_rank: Optional[int] = None,
@@ -105,10 +109,12 @@ class ManagedJob:
         """The SEV2 path: the agent reports the crash, the iteration
         completes through ``iteration_grads`` with exact semantics, and the
         optimizer applies it.  Returns (state, grad_norm)."""
-        self.agent.report(ErrorKind.EXITED_ABNORMALLY, now=float(step))
-        grad_sum, count = self.iteration_grads(state.params, step, fail_rank,
-                                               fail_after_mb)
-        return finalize_step(self.opt, state, grad_sum, count)
+        with obs.span("sev2.iteration", step=step):
+            self.agent.report(ErrorKind.EXITED_ABNORMALLY, now=float(step))
+            grad_sum, count = self.iteration_grads(
+                state.params, step, fail_rank, fail_after_mb)
+            with obs.span("sev2.optimizer", step=step):
+                return finalize_step(self.opt, state, grad_sum, count)
 
 
 def build_job(cfg: ArchConfig, *, seq: int, batch: int, n_micro: int,
@@ -140,30 +146,37 @@ def run(job: ManagedJob, state: TrainState, steps: int, *, start: int = 0,
     ``inject_fail`` runs the SEV2 path (``recovered_step``); every other
     step is the fused step, its time fed to the agent's monitor.  After
     every ``job.ckpt_every``-th completed step the state is saved (both
-    tiers).  Each step's time ends in ``block_until_ready``.  Returns the
-    state and one record per step."""
+    tiers).  Each step's time runs from the start of its ``loop.step`` span
+    to the end of its ``loop.sync`` span (``block_until_ready`` and the
+    host reads of its numbers).  Returns the state and one record per
+    step."""
     records = []
     for step in range(start, start + steps):
-        t0 = time.perf_counter()
-        if step == inject_fail:
-            log(f"step {step}: INJECTING rank-1 failure mid-iteration")
-            state, gnorm = job.recovered_step(state, step)
-            rec = {"step": step, "kind": "recovered", "loss": None,
-                   "grad_norm": float(jax.block_until_ready(gnorm))}
-        else:
-            state, metrics = jax.block_until_ready(job.step(state, step))
-            rec = {"step": step, "kind": "fused",
-                   "loss": float(metrics["loss"]),
-                   "grad_norm": float(metrics["grad_norm"])}
-        rec["seconds"] = time.perf_counter() - t0
-        if rec["kind"] == "fused":
-            job.agent.observe_iteration(rec["seconds"])
-        rec["saved"] = (step + 1) % job.ckpt_every == 0
-        if rec["saved"]:
-            job.mgr.save(rank=0, step=step + 1, state=state)
-        log(f"step {step:4d} {rec['kind']} loss={rec['loss']} "
-            f"grad_norm={rec['grad_norm']:.4f} ({rec['seconds']:.3f}s)"
-            + (" saved" if rec["saved"] else ""))
+        kind = "recovered" if step == inject_fail else "fused"
+        with obs.span("loop.step", step=step, kind=kind) as whole:
+            if kind == "recovered":
+                log(f"step {step}: INJECTING rank-1 failure mid-iteration")
+                state, gnorm = job.recovered_step(state, step)
+                with obs.span("loop.sync", step=step) as sync:
+                    rec = {"step": step, "kind": kind, "loss": None,
+                           "grad_norm": float(jax.block_until_ready(gnorm))}
+            else:
+                state, metrics = job.step(state, step)
+                with obs.span("loop.sync", step=step) as sync:
+                    state, metrics = jax.block_until_ready((state, metrics))
+                    rec = {"step": step, "kind": kind,
+                           "loss": float(metrics["loss"]),
+                           "grad_norm": float(metrics["grad_norm"])}
+            rec["seconds"] = sync.t1 - whole.t0
+            if kind == "fused":
+                with obs.span("loop.monitor", step=step):
+                    job.agent.observe_iteration(rec["seconds"])
+            rec["saved"] = (step + 1) % job.ckpt_every == 0
+            if rec["saved"]:
+                job.mgr.save(rank=0, step=step + 1, state=state)
+            log(f"step {step:4d} {rec['kind']} loss={rec['loss']} "
+                f"grad_norm={rec['grad_norm']:.4f} ({rec['seconds']:.3f}s)"
+                + (" saved" if rec["saved"] else ""))
         records.append(rec)
     return state, records
 
