@@ -15,7 +15,9 @@ One process, phases in order; any failed check raises and exits non-zero.
     restores from the in-memory and the persistent tier that equal the
     saved state bit for bit, and one injected SEV2 iteration whose gradient
     equals the fault-free iteration's up to f32 summation order and whose
-    step agrees with the fused fault-free step.
+    step agrees with the fused fault-free step; the in-memory snapshot,
+    which keeps the transfer's own host arrays, is unchanged after three
+    donated steps and the next save.
 (c) The planner's device program: a fused ``PlanTable`` whole-table
     rebuild at the paper's headline fleet (n=1024, m=32) in one dispatch,
     with no retrace on a same-signature rebuild, within 1e-6 of
@@ -47,6 +49,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.checkpoint import inmemory  # noqa: E402
 from repro.configs.qwen3_4b import ONE_CHIP_CUT, one_chip_share  # noqa: E402
 from repro.kernels.pallas_config import resolve_interpret  # noqa: E402
 from repro.launch.train import build_job, run, use_compile_cache  # noqa: E402
@@ -177,6 +180,9 @@ def train_phase() -> None:
         mem, at, src = job.mgr.restore(0, like=state)
         check(src == "inmemory_local" and at == 3, f"in-memory tier: {src}")
         check(bit_equal(mem, state), "in-memory restore differs")
+        check(not any(inmemory.copies(x) for x in jax.tree.leaves(state)),
+              "the snapshot copies leaves on the chip")
+        frozen = jax.tree.map(np.array, mem)
         job.mgr.drop_rank(0)                    # the host and its neighbour
         job.mgr.drop_rank(job.mgr.store.neighbor(0))
         t0 = time.perf_counter()
@@ -199,8 +205,19 @@ def train_phase() -> None:
                               log=lambda s: None)
         check(int(rec_state.step) == 4, "recovered step counter")
         del state, rec_state
-        _, ff = job.step(jax.device_put(mem), 3)          # fault-free
+        state, ff = job.step(jax.device_put(mem), 3)      # fault-free
         gn_err = rel(recs[0]["grad_norm"], ff["grad_norm"])
+        # 5. the snapshot keeps the transfer's host arrays: the donated
+        # fault-free step, two more and the next in-memory save leave its
+        # bytes as they were
+        job.mgr.persist_every = 100        # the persistent tier is checked
+        state, recs2 = run(job, state, 2, start=4, log=lambda s: None)
+        check(recs2[-1]["saved"], "no second checkpoint save")
+        check(bit_equal(mem, frozen), "a snapshot changed after it was "
+              "taken")
+        del state, frozen
+        emit("train.snapshot", unchanged="bit-equal", donated_steps_after=3,
+             saves_after=1)
         emit("train.sev2", recovered_s=recs[0]["seconds"],
              grad_sum_rel_l2=grad_err, grad_rtol=GRAD_RTOL,
              grad_norm_vs_fused_rel=gn_err, bf16_rtol=BF16_RTOL,

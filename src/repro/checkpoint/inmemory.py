@@ -19,18 +19,39 @@ import numpy as np
 from repro import obs
 
 
+def copies(x: Any) -> bool:
+    """Whether the snapshot copies ``x``'s host array after the transfer.
+
+    A ``jax.Array`` on an accelerator transfers into a fresh host array
+    the runtime makes for that transfer, which nothing else writes: the
+    snapshot keeps it.  On a CPU device the transfer is a view of the
+    device buffer (and ``jax.device_put`` of a numpy array may alias it
+    in turn), memory that a donated step consumes: a snapshot holding it
+    would share it with the live state and keep the step from donating
+    it.  A numpy or Python leaf is the caller's own object.  Those two
+    are copied."""
+    return not (isinstance(x, jax.Array)
+                and all(d.platform != "cpu" for d in x.devices()))
+
+
 def _leaf_to_host(x: Any) -> np.ndarray:
-    """``np.array(x)`` in its two steps: the device-to-host transfer into
-    the runtime's host buffer, then the copy into an array the snapshot
-    owns."""
+    """The device-to-host transfer into a host array and, where
+    ``copies(x)``, the copy of it into an array the snapshot owns."""
     with obs.span("ckpt.d2h"):
         host = np.asarray(x)
+    if not copies(x):
+        return host
     with obs.span("ckpt.host_copy"):
         return np.array(host)
 
 
 def _snapshot(tree: Any) -> Any:
-    """Copy a pytree to host memory (numpy)."""
+    """Copy a pytree to host memory (numpy).  CPU and numpy leaves are
+    copied after the transfer, since their host array may alias memory
+    that a donated step consumes; a leaf from an accelerator keeps the
+    fresh host array of its transfer, which JAX marks read-only.  So
+    snapshot leaves may be read-only: nothing writes into a snapshot (the
+    persistent tier, restore and ``jax.device_put`` only read it)."""
     return jax.tree.map(_leaf_to_host, tree)
 
 

@@ -39,10 +39,17 @@ class CheckpointManager:
         """In-memory snapshot every call; async spool to persistent tier
         every ``persist_every`` steps (synchronous here; the simulator
         models the asynchrony).  The persistent tier writes the host
-        snapshot, so the state crosses from the device once."""
+        snapshot, so the state crosses from the device once.  The span's
+        ``copied_bytes`` are those the snapshot copied on the host after
+        the transfer (``inmemory.copies``): all on the CPU, none from an
+        accelerator."""
         with obs.span("ckpt.save", step=step) as sp:
             snap = self.store.put(self.task, rank, step, state)
-            sp.attrs["bytes"] = sum(x.nbytes for x in jax.tree.leaves(snap))
+            sizes = [x.nbytes for x in jax.tree.leaves(snap)]
+            sp.attrs["bytes"] = sum(sizes)
+            sp.attrs["copied_bytes"] = sum(
+                n for x, n in zip(jax.tree.leaves(state), sizes)
+                if inmemory.copies(x))
             if step % self.persist_every == 0:
                 with obs.span("ckpt.persist", step=step):
                     persistent.save(self.directory, step, snap)

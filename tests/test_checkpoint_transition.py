@@ -72,6 +72,25 @@ def test_inmemory_ring_replication(state):
     _close(hit[1], state)
 
 
+def test_cpu_snapshot_survives_a_donated_step(tmp_path, state):
+    """On the CPU the transfer is a view of the device buffer: the
+    snapshot copies it, so it holds no reference that keeps a step from
+    donating the saved state, and the step leaves it as it was."""
+    mgr = CheckpointManager(str(tmp_path), n_ranks=2, persist_every=100,
+                            task="t")
+    mgr.save(rank=0, step=1, state=state)
+    want = {k: np.array(x) for k, x in state.items()}
+    step = jax.jit(lambda s: jax.tree.map(lambda x: x * 3.0 + 1.0, s),
+                   donate_argnums=0)
+    new = jax.block_until_ready(step(state))
+    assert all(x.is_deleted() for x in state.values())
+    snap, at, src = mgr.restore(0, like=None)
+    assert (at, src) == (1, "inmemory_local")
+    for k, x in want.items():
+        assert snap[k].tobytes() == x.tobytes()
+    assert not np.array_equal(np.asarray(new["b"]), want["b"])
+
+
 def test_nearest_principle_ordering(tmp_path, state):
     """DP replica beats in-memory beats persistent."""
     mgr = CheckpointManager(str(tmp_path), n_ranks=4, persist_every=1,

@@ -11,6 +11,7 @@ import pytest
 
 from repro import obs
 from repro.checkpoint import inmemory
+from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch
 from repro.core.agent import UnicronAgent
 from repro.core.cluster import Cluster
@@ -139,6 +140,41 @@ def test_split_snapshot_is_np_array_and_owns_its_memory(rec):
                                     ("ckpt.host_copy", None): 3}
 
 
+class _DeviceLeaf(jax.Array):
+    """A stand-in for an array on an accelerator whose transfer makes
+    ``host``."""
+
+    def __init__(self, host):
+        self.host = host
+
+    def devices(self):
+        return {type("Device", (), {"platform": "tpu"})()}
+
+    def __array__(self, dtype=None, copy=None):
+        return self.host
+
+
+def test_snapshot_keeps_an_accelerator_leafs_transfer(rec, tmp_path):
+    host = np.arange(12, dtype=np.float32).reshape(3, 4)
+    tree = {"w": _DeviceLeaf(host), "h": np.ones(5, np.float32)}
+    mgr = CheckpointManager(str(tmp_path), n_ranks=2, persist_every=100,
+                            task="t")
+    mgr.save(rank=0, step=1, state=tree)
+    snap = mgr.store.get("t", 0)[1]
+    assert snap["w"] is host
+    assert snap["h"] is not tree["h"]
+    assert not np.shares_memory(snap["h"], tree["h"])
+    assert _tree(obs.records()) == {("ckpt.save", None): 1,
+                                    ("ckpt.d2h", "ckpt.save"): 2,
+                                    ("ckpt.host_copy", "ckpt.save"): 1,
+                                    ("ckpt.release", "ckpt.save"): 1}
+    (save,) = [r for r in obs.records() if r.name == "ckpt.save"]
+    assert save.attrs == {"step": 1, "bytes": host.nbytes + 20,
+                          "copied_bytes": 20}
+    assert not inmemory.copies(tree["w"])
+    assert inmemory.copies(tree["h"]) and inmemory.copies(jnp.ones(2))
+
+
 def _coordinator(**kw):
     tasks = [Task(model=TaskModel.from_arch(get_arch(a), global_batch=64))
              for a in ("gpt3-1.3b", "gpt3-7b")]
@@ -238,8 +274,9 @@ def test_run_records_the_managed_loop_tree(rec, tmp_path):
     for r, s in zip(recs, steps):
         assert r["seconds"] == sync[r["step"]].t1 - s.t0
     (save,) = [r for r in spans if r.name == "ckpt.save"]
-    assert save.attrs == {"step": 2, "bytes": sum(
-        x.nbytes for x in jax.tree.leaves(state))}
+    n_bytes = sum(x.nbytes for x in jax.tree.leaves(state))
+    assert save.attrs == {"step": 2, "bytes": n_bytes,
+                          "copied_bytes": n_bytes}
     # rank 1 of 2 dies before its first micro-batch: rank 0 takes its two,
     # which nobody had computed
     grads = [r.attrs for r in spans if r.name == "sev2.grads"]
