@@ -120,6 +120,8 @@ class ArchConfig:
     mtp: bool = False                  # DeepSeek multi-token-prediction head
     embed_scale: bool = False          # gemma: scale embeddings by sqrt(d)
     param_dtype: str = "bfloat16"
+    norm_eps: float = 1e-6             # every RMSNorm / LayerNorm epsilon
+    residual_in_fp32: bool = False     # mamba: float32 residual stream
 
     # ---- derived helpers ---------------------------------------------------
 
@@ -145,7 +147,8 @@ class ArchConfig:
         return ((BLOCK_ATTN_DENSE, self.n_layers),)
 
     def param_count(self) -> int:
-        """Approximate parameter count N (used for 6*N*D roofline check)."""
+        """Parameter count N (used for 6*N*D roofline check); exact for
+        RMSNorm attention and Mamba2 blocks."""
         d = self.d_model
         n = 0
         n += self.vocab * d                       # embedding
@@ -190,6 +193,8 @@ class ArchConfig:
         p = d * a.n_heads * a.head_dim            # q
         p += 2 * d * a.n_kv_heads * a.head_dim    # k, v
         p += a.n_heads * a.head_dim * d           # o
+        if a.qk_norm:
+            p += 2 * a.head_dim                   # q_norm, k_norm
         return p
 
     def _mlp_params(self, d_ff: int) -> int:
@@ -205,9 +210,10 @@ class ArchConfig:
             nh = s.n_heads(d)
             # in_proj produces [z, x, B, C, dt]: di + di + 2*n_groups*d_state + nh
             n_groups = 1
+            conv_ch = di + 2 * n_groups * s.d_state
             p = d * (2 * di + 2 * n_groups * s.d_state + nh)
-            p += s.d_conv * (di + 2 * n_groups * s.d_state)   # conv1d
-            p += nh * 2                                       # A_log, D
+            p += (s.d_conv + 1) * conv_ch                     # conv1d + bias
+            p += nh * 3                                       # dt_bias, A_log, D
             p += di                                           # gate norm
             p += di * d                                       # out proj
             return p + d                                      # + pre-norm

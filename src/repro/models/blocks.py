@@ -75,22 +75,30 @@ def init_shared_block(key, cfg, dtype) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def pre_norm(p: dict, cfg, x):
+    """A block's norm of the residual stream ``x``, computed in float32
+    and handed on in the weights' type (a no-op cast unless the stream is
+    float32, ``cfg.residual_in_fp32``)."""
+    return layers.norm_apply(p, x, cfg.norm,
+                             cfg.norm_eps).astype(p["scale"].dtype)
+
+
 def block_apply(p: dict, cfg, kind: str, x, positions, *,
                 layer_is_local: bool = False, kernel: str = "jnp"):
     """Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (BLOCK_MAMBA, BLOCK_HYBRID_SHARED):
-        h = layers.norm_apply(p["norm"], x, cfg.norm)
+        h = pre_norm(p["norm"], cfg, x)
         x = x + ssm.mamba_apply(p["mamba"], cfg, h, kernel=kernel)
         return x, aux
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    h = pre_norm(p["norm1"], cfg, x)
     if has_mla(kind):
         x = x + mla.mla_apply(p["attn"], cfg, h, positions, kernel=kernel)
     else:
         x = x + layers.attention_apply(p["attn"], cfg, h,
                                        layer_is_local=layer_is_local,
                                        positions=positions, kernel=kernel)
-    h = layers.norm_apply(p["norm2"], x, cfg.norm)
+    h = pre_norm(p["norm2"], cfg, x)
     if has_moe(kind):
         y, aux = moe.moe_apply(p["moe"], cfg, h)
         x = x + y
@@ -100,10 +108,10 @@ def block_apply(p: dict, cfg, kind: str, x, positions, *,
 
 
 def shared_block_apply(p: dict, cfg, x, positions, kernel: str = "jnp"):
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    h = pre_norm(p["norm1"], cfg, x)
     x = x + layers.attention_apply(p["attn"], cfg, h, layer_is_local=False,
                                    positions=positions, kernel=kernel)
-    h = layers.norm_apply(p["norm2"], x, cfg.norm)
+    h = pre_norm(p["norm2"], cfg, x)
     x = x + layers.mlp_apply(p["mlp"], h, cfg.mlp_act, cfg.gated_mlp)
     return x
 
@@ -132,10 +140,10 @@ def block_decode(p: dict, cfg, kind: str, x, cache: dict, pos, *,
                  layer_is_local: bool = False):
     """One-token decode.  x: (B,1,d).  Returns (x, new_cache)."""
     if kind in (BLOCK_MAMBA, BLOCK_HYBRID_SHARED):
-        h = layers.norm_apply(p["norm"], x, cfg.norm)
+        h = pre_norm(p["norm"], cfg, x)
         y, new = ssm.mamba_decode(p["mamba"], cfg, h, cache)
         return x + y, new
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    h = pre_norm(p["norm1"], cfg, x)
     if has_mla(kind):
         y, new = mla.mla_decode(p["attn"], cfg, h, cache, pos)
     else:
@@ -144,7 +152,7 @@ def block_decode(p: dict, cfg, kind: str, x, cache: dict, pos, *,
                                             layer_is_local=layer_is_local)
         new = {"k": nk, "v": nv}
     x = x + y
-    h = layers.norm_apply(p["norm2"], x, cfg.norm)
+    h = pre_norm(p["norm2"], cfg, x)
     if has_moe(kind):
         y2, _ = moe.moe_apply(p["moe"], cfg, h)
         x = x + y2
@@ -154,10 +162,10 @@ def block_decode(p: dict, cfg, kind: str, x, cache: dict, pos, *,
 
 
 def shared_block_decode(p: dict, cfg, x, cache: dict, pos):
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    h = pre_norm(p["norm1"], cfg, x)
     y, nk, nv = layers.attention_decode(p["attn"], cfg, h, cache["k"],
                                         cache["v"], pos, layer_is_local=False)
     x = x + y
-    h = layers.norm_apply(p["norm2"], x, cfg.norm)
+    h = pre_norm(p["norm2"], cfg, x)
     x = x + layers.mlp_apply(p["mlp"], h, cfg.mlp_act, cfg.gated_mlp)
     return x, {"k": nk, "v": nv}

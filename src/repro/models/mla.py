@@ -38,7 +38,8 @@ def _project_q(p, cfg, x, positions):
     H = cfg.attn.n_heads
     dn, dr = m.qk_nope_head_dim, m.qk_rope_head_dim
     B, S, _ = x.shape
-    cq = layers.rms_norm_weighted(x @ p["w_dq"], p["q_norm"])
+    cq = layers.rms_norm_weighted(x @ p["w_dq"], p["q_norm"],
+                                  cfg.norm_eps)
     q = (cq @ p["w_uq"]).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = layers.apply_rope(q_rope, positions, cfg.attn.rope_theta)
@@ -50,7 +51,7 @@ def _project_kv_latent(p, cfg, x, positions):
     dr = m.qk_rope_head_dim
     ckv_full = x @ p["w_dkv"]
     ckv = layers.rms_norm_weighted(ckv_full[..., :m.kv_lora_rank],
-                                   p["kv_norm"])
+                                   p["kv_norm"], cfg.norm_eps)
     k_rope = layers.apply_rope(ckv_full[..., m.kv_lora_rank:], positions,
                                cfg.attn.rope_theta)          # (B,S,dr)
     return ckv, k_rope

@@ -156,6 +156,9 @@ def build_model(cfg: ArchConfig) -> Model:
             }
         return params
 
+    def _residual(x):
+        return x.astype(jnp.float32) if cfg.residual_in_fp32 else x
+
     def _head_w(params):
         return params["embed"]["w"] if cfg.tie_embeddings \
             else params["head"]["w"]
@@ -170,7 +173,7 @@ def build_model(cfg: ArchConfig) -> Model:
         if cfg.modality == "vision_stub":
             pre = batch["prefix_embeds"].astype(x.dtype)
             x = jnp.concatenate([pre, x], axis=1)
-        return x
+        return _residual(x)
 
     # ---------------- forward ----------------
 
@@ -204,7 +207,7 @@ def build_model(cfg: ArchConfig) -> Model:
         S = x.shape[1]
         positions = jnp.arange(S, dtype=jnp.int32)
         x, aux = _run_segments(params, x, positions, kernel, remat)
-        h = layers.norm_apply(params["final_norm"], x, cfg.norm)
+        h = blocks.pre_norm(params["final_norm"], cfg, x)
         if last_logits_only:
             # Serving prefill: only the last position's logits are needed
             # (full-seq logits at 32k x 262k vocab would be infeasible).
@@ -216,7 +219,7 @@ def build_model(cfg: ArchConfig) -> Model:
             hm, _ = blocks.block_apply(
                 params["mtp"]["block"], cfg,
                 BLOCK_MLA_DENSE if cfg.mla else segs[0].kind, x, positions)
-            hm = layers.norm_apply(params["mtp"]["norm"], hm, cfg.norm)
+            hm = blocks.pre_norm(params["mtp"]["norm"], cfg, hm)
             extras["mtp_logits"] = layers.logits_apply(_head_w(params), hm)
         return logits, extras
 
@@ -275,8 +278,8 @@ def build_model(cfg: ArchConfig) -> Model:
     def decode_step(params, caches, tokens, pos):
         """tokens: (B,) int32; pos: scalar int32 (absolute position).
         Returns (logits (B, vocab) f32, new_caches)."""
-        x = layers.embed_apply(params["embed"], tokens[:, None],
-                               cfg.embed_scale, cfg.d_model)
+        x = _residual(layers.embed_apply(params["embed"], tokens[:, None],
+                                         cfg.embed_scale, cfg.d_model))
         new_caches = []
         for seg, slot_params, cache in zip(segs, params["segments"], caches):
             shared_p = params.get("shared")
@@ -297,7 +300,7 @@ def build_model(cfg: ArchConfig) -> Model:
 
             x, new_cache = lax.scan(body, x, (slot_params, cache))
             new_caches.append(new_cache)
-        h = layers.norm_apply(params["final_norm"], x, cfg.norm)
+        h = blocks.pre_norm(params["final_norm"], cfg, x)
         logits = layers.logits_apply(_head_w(params), h)[:, 0]
         return logits, new_caches
 
