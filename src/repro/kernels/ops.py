@@ -1,14 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Each op runs the Pallas forward kernel and differentiates through a
-pure-jnp implementation via ``jax.custom_vjp`` — standard practice for
-forward-optimized kernels: the backward pass recomputes from jnp code
-that agrees with the kernel output to float tolerance (asserted by
-tests/test_kernels.py).  Attention recomputes through the flash-style
-custom VJP of ``models.flash_vjp``, so its backward keeps nothing of size
-O(S^2); differentiating the blocked oracle would stack every block's
-probabilities (6 GB a layer at 32 heads x 4096 tokens).  SSD and
-RMSNorm differentiate their oracles in ``ref.py``.
+Each op runs the Pallas forward kernel under a ``jax.custom_vjp``.
+Attention and RMSNorm differentiate pure-jnp code that agrees with the
+kernel output to float tolerance (asserted by tests/test_kernels.py).
+Attention recomputes through the flash-style custom VJP of
+``models.flash_vjp``, so its backward keeps nothing of size O(S^2);
+differentiating the blocked oracle would stack every block's
+probabilities (6 GB a layer at 32 heads x 4096 tokens).  RMSNorm
+differentiates its oracle in ``ref.py``.  The SSD scan has a Pallas
+backward kernel of its own (``ssd_scan.ssd_scan_bwd``); its oracle in
+``ref.py`` is the tests' reference for both directions.
 
 ``interpret`` resolution lives in ``pallas_config.resolve_interpret``: the
 kernels compile on TPU (Mosaic) and interpret everywhere else, with
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
-from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.kernels.ssd_scan import ssd_scan_bwd, ssd_scan_fwd
 from repro.models.flash_vjp import flash_attention_jnp
 
 
@@ -73,11 +74,8 @@ def _ssd_fwd(x, dt, A, Bm, Cm, chunk):
 
 
 def _ssd_bwd(chunk, res, g):
-    x, dt, A, Bm, Cm = res
-    _, vjp = jax.vjp(
-        lambda x, dt, A, Bm, Cm: ref.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk),
-        x, dt, A, Bm, Cm)
-    return vjp(g)
+    dy, dfin = g
+    return ssd_scan_bwd(*res, dy, dfin, chunk=chunk)
 
 
 ssd_scan.defvjp(_ssd_fwd, _ssd_bwd)

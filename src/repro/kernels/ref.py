@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the ground truth the kernel tests ``assert_allclose`` against
-(and the backward functions of the SSD and RMSNorm custom VJPs).  They
+(and the backward function of the RMSNorm custom VJP).  They
 intentionally share code with the model's own jnp paths so that switching
 ``kernel="jnp" -> "pallas"`` is a pure performance change.
 """

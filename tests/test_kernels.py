@@ -90,6 +90,9 @@ SSD_CASES = [
     (1, 128, 4, 8, 2, 8, 128),       # multi-group, single chunk
     (2, 37, 2, 8, 1, 4, 16),         # S < 2 chunks, ragged
 ]
+SSD_GRAD_CASES = SSD_CASES + [
+    (1, 512, 2, 64, 1, 128, 256),    # mamba2-780m's chunk and state
+]
 
 
 @pytest.mark.parametrize("B,S,H,P,G,N,chunk", SSD_CASES)
@@ -104,6 +107,71 @@ def test_ssd_scan_matches_oracle(B, S, H, P, G, N, chunk):
     yr, finr = ref.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
     np.testing.assert_allclose(y, yr, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(fin, finr, atol=1e-4, rtol=1e-4)
+
+
+def _no_oracle(monkeypatch):
+    """Make any use of the jnp oracle raise: the kernel's custom VJP must
+    not fall back to differentiating it."""
+    from repro.models import ssm
+
+    def oracle(*a, **kw):
+        raise AssertionError("ssd_scan's backward used the jnp oracle")
+    monkeypatch.setattr(ref, "ssd_scan", oracle)
+    monkeypatch.setattr(ref, "ssd_chunked", oracle)
+    monkeypatch.setattr(ssm, "ssd_chunked", oracle)
+
+
+@pytest.mark.parametrize("B,S,H,P,G,N,chunk", SSD_GRAD_CASES)
+def test_ssd_scan_grad_matches_oracle(B, S, H, P, G, N, chunk, monkeypatch):
+    """The Pallas backward's cotangents of all five inputs against
+    ``jax.vjp`` of the oracle, with random cotangents on y and on the
+    final state."""
+    ks = jax.random.split(jax.random.fold_in(KEY, S * H + P), 7)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    g = (jax.random.normal(ks[5], (B, S, H, P)),
+         jax.random.normal(ks[6], (B, H, P, N)))
+    args = (x, dt, A, Bm, Cm)
+    _, vjp = jax.vjp(lambda *a: ref.ssd_scan(*a, chunk=chunk), *args)
+    want = vjp(g)
+    _no_oracle(monkeypatch)
+    _, vjp = jax.vjp(lambda *a: ops.ssd_scan(*a, chunk), *args)
+    got = vjp(g)
+    for name, a, b in zip(("x", "dt", "A", "B", "C"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=1e-4 * scale, rtol=1e-4,
+                                   err_msg=name)
+
+
+def test_ssd_scan_grad_is_finite_under_strong_decay(monkeypatch):
+    """The published A (down to -16) at dt 0.1 decays by e^-1.6 a token:
+    over a 256-token chunk the exponent above the diagonal overflows and
+    exp(acum) underflows, and the kernel's gradient stays finite and
+    equal to the oracle's."""
+    S, H, P, N, chunk = 512, 2, 8, 16, 256
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (1, S, H, P))
+    Bm = jax.random.normal(ks[1], (1, S, 1, N))
+    Cm = jax.random.normal(ks[2], (1, S, 1, N))
+    args = (jnp.full((1, S, H), 0.1), jnp.array([-16.0, -1.0]))
+
+    def loss(scan):
+        return lambda dt, A: sum(
+            o.sum() for o in scan(x, dt, A, Bm, Cm))
+
+    want = jax.grad(loss(lambda *a: ref.ssd_scan(*a, chunk=chunk)),
+                    argnums=(0, 1))(*args)
+    _no_oracle(monkeypatch)
+    got = jax.grad(loss(lambda *a: ops.ssd_scan(*a, chunk)),
+                   argnums=(0, 1))(*args)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(b))))
 
 
 def test_ssd_scan_matches_serial_recurrence():

@@ -18,7 +18,7 @@ from repro.configs import get_arch
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.maxplus import maxplus_conv_batched, maxplus_scan_chunk
 from repro.kernels.rmsnorm import rmsnorm_fwd
-from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.kernels.ssd_scan import ssd_scan_bwd, ssd_scan_fwd
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +71,19 @@ def _mamba2_780m_ssd():
              ((1, seq, 1, s.d_state), jnp.float32)])
 
 
+def _ssd_bwd(arch: str, seq: int = 4096):
+    """SSD backward kernel at an architecture's widths: the forward's
+    operands, then the cotangents of y and of the final state."""
+    cfg = get_arch(arch)
+    s = cfg.ssm
+    h, p, n = s.n_heads(cfg.d_model), s.head_dim, s.d_state
+    return (partial(ssd_scan_bwd, chunk=s.chunk, interpret=False),
+            [((1, seq, h, p), jnp.float32), ((1, seq, h), jnp.float32),
+             ((h,), jnp.float32), ((1, seq, 1, n), jnp.float32),
+             ((1, seq, 1, n), jnp.float32), ((1, seq, h, p), jnp.float32),
+             ((1, h, p, n), jnp.float32)])
+
+
 # the fused planner's inner step at the headline fleet (n=1024, m=32):
 # 32 stacked windows of n+1 + K-1 cells, chunk K=16
 def _planner_scan_chunk():
@@ -89,6 +102,8 @@ CASES = {
     "flash_attention_qwen3_4b": _qwen3_4b_attention,
     "rmsnorm_qwen3_4b": _qwen3_4b_rmsnorm,
     "ssd_scan_mamba2_780m": _mamba2_780m_ssd,
+    "ssd_scan_bwd_mamba2_780m": partial(_ssd_bwd, "mamba2-780m"),
+    "ssd_scan_bwd_zamba2_1p2b": partial(_ssd_bwd, "zamba2-1.2b"),
     "maxplus_scan_chunk_n1024_m32": _planner_scan_chunk,
     "maxplus_conv_batched_n1024_m32": _planner_conv_batched,
 }
@@ -101,3 +116,28 @@ def test_kernel_compiles_for_v5e(one_chip, case):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_backward_operands_are_chunk_blocked(one_chip):
+    """The backward kernel takes x and B/C in the chunk-blocked layout
+    (B, H or G, chunks, L, .), never the forward's head-major
+    (B, H or G, S, .) shapes, so that a trace tells the two kernels
+    apart by their operands' shapes."""
+    cfg = get_arch("mamba2-780m")
+    s = cfg.ssm
+    h, seq = s.n_heads(cfg.d_model), 4096
+    head_major = (f"f32[1,{h},{seq},{s.head_dim}]",
+                  f"f32[1,1,{seq},{s.d_state}]")
+
+    def kernel_line(case):
+        fn, shapes = case()
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        (line,) = [ln for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        return line
+
+    assert all(sh in kernel_line(_mamba2_780m_ssd) for sh in head_major)
+    bwd = kernel_line(partial(_ssd_bwd, "mamba2-780m"))
+    assert not any(sh in bwd for sh in head_major)
